@@ -24,8 +24,6 @@ import time
 
 
 def main() -> int:
-    import jax
-
     from benchmarks.roofline import (
         HBM_BW,
         ICI_BW,
@@ -35,6 +33,7 @@ def main() -> int:
     from repro.configs import get
     from repro.distributed.sharding import FSDP_TP
     from repro.launch.hlo_analysis import collective_stats, loop_aware_cost
+    from repro.launch.mesh import make_mesh
     from repro.launch.steps import build_lowerable
     from repro.training.train_loop import TrainConfig
 
@@ -56,7 +55,7 @@ def main() -> int:
     args = p.parse_args()
 
     dims = tuple(int(x) for x in args.mesh_shape.split(","))
-    mesh = jax.make_mesh(dims, ("data", "model"))
+    mesh = make_mesh(dims, ("data", "model"))
 
     spec = get(args.arch)
     cfg = spec.model

@@ -1,6 +1,7 @@
 """Public jit'd wrappers around the Pallas kernels.
 
-``fused_tenant_gemm`` is the host-facing API the serving engine uses: it
+``fused_tenant_gemm`` is the host-facing API (``chip_smoke.py``,
+``benchmarks/kernel_bench.py`` and ``examples/quickstart.py`` call it): it
 takes one (x, w) GEMM per tenant — arbitrary ragged shapes — pads them to a
 shared grid geometry, builds the column-block ``owner`` map with the SAME
 column-splitting rule as Algorithm 1 (``partition_calculation`` over N

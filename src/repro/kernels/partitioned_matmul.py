@@ -57,23 +57,16 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# renamed TPUCompilerParams -> CompilerParams across jax releases
-_CompilerParams = getattr(pltpu, "CompilerParams",
-                          getattr(pltpu, "TPUCompilerParams", None))
-if _CompilerParams is None:  # pragma: no cover - depends on jax version
-    raise ImportError(
-        "jax.experimental.pallas.tpu exposes neither CompilerParams nor "
-        "TPUCompilerParams; this jax version is unsupported by "
-        "repro.kernels.partitioned_matmul")
-
 # MXU/VREG-aligned defaults: 128-multiples on the matmul dims.
 DEFAULT_BLOCK_T = 128
 DEFAULT_BLOCK_K = 128
 DEFAULT_BLOCK_N = 128
 
-# Per-core VMEM capacity the block working set must fit in (TPU v3/v4 class
-# hardware carries ~16 MiB of VMEM per core).  ``partitioned_matmul``
-# enforces this budget explicitly — see :func:`block_vmem_bytes`.
+# Scoped VMEM a kernel may allocate: Mosaic's default limit, 16 MiB on TPU
+# v5e (an ahead-of-time v5e compile refuses a 1024³ f32 block with "limit
+# 16.00M").  :func:`block_vmem_bytes` counts every tile double-buffered,
+# at or above Mosaic's own allocation, so a working set within this budget
+# stays within that limit; ``partitioned_matmul`` enforces it explicitly.
 VMEM_BUDGET_BYTES = 16 * 2 ** 20
 
 _ALLOWED_DTYPES = (jnp.dtype(jnp.bfloat16), jnp.dtype(jnp.float32))
@@ -254,6 +247,21 @@ def grid_accounting(*, T: int, K: int, N: int, owner, valid_t, valid_k=None,
         out_bytes_written=runs * block_t * block_n * 4)
 
 
+def _block_dot(x: jax.Array, w: jax.Array) -> jax.Array:
+    """One block's MXU product, accumulated in f32.
+
+    f32 operands ask for a full-f32 contraction: at Mosaic's default
+    precision a TPU v5e rounds them to bf16 (2.4e-3 relative error against
+    an f32 reference at K=256).  bf16 products are exact in f32 either way.
+    """
+    precision = (jax.lax.Precision.HIGHEST if x.dtype == jnp.float32
+                 else None)
+    return jax.lax.dot_general(x, w,
+                               dimension_numbers=(((1,), (0,)), ((), ())),
+                               precision=precision,
+                               preferred_element_type=jnp.float32)
+
+
 # ---------------------------------------------------------------------------
 # dense grid (every (n, t, k) scheduled; dead blocks gated by pl.when)
 # ---------------------------------------------------------------------------
@@ -278,10 +286,7 @@ def _dense_kernel(owner_ref, valid_t_ref, valid_k_ref, x_ref, w_ref, o_ref,
 
     @pl.when(live)
     def _mac():
-        acc_ref[...] += jax.lax.dot_general(
-            x_ref[0], w_ref[...],
-            dimension_numbers=(((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        acc_ref[...] += _block_dot(x_ref[0], w_ref[...])
 
     @pl.when(k == n_k_blocks - 1)
     def _drain():
@@ -321,7 +326,7 @@ def _dense_call(xs: jax.Array, w: jax.Array, owner: jax.Array,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((T, N), jnp.float32),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(owner.astype(jnp.int32), valid_t.astype(jnp.int32),
@@ -341,10 +346,7 @@ def _compact_kernel(xidx_ref, nidx_ref, tidx_ref, kidx_ref, last_ref,
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    acc_ref[...] += jax.lax.dot_general(
-        x_ref[0], w_ref[...],
-        dimension_numbers=(((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
+    acc_ref[...] += _block_dot(x_ref[0], w_ref[...])
 
     @pl.when(last_ref[i] == 1)
     def _drain():
@@ -382,7 +384,7 @@ def _compact_call(xs: jax.Array, w: jax.Array, owner: np.ndarray,
         _compact_kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((T, N), jnp.float32),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(jnp.asarray(xidx), jnp.asarray(nidx), jnp.asarray(tidx),

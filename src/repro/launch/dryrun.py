@@ -35,7 +35,7 @@ def _run():
 
     from repro.configs import ARCHS, get
     from repro.launch.hlo_analysis import collective_stats, loop_aware_cost
-    from repro.launch.mesh import make_production_mesh
+    from repro.launch.mesh import make_mesh, make_production_mesh
     from repro.launch.steps import build_lowerable
     from repro.training.train_loop import TrainConfig
 
@@ -65,8 +65,6 @@ def _run():
 
     import dataclasses
 
-    import jax as _jax
-
     from repro.distributed.sharding import choose_mesh_shape
 
     n_ok = n_fail = 0
@@ -86,7 +84,7 @@ def _run():
                          else (data_w, model_w))
                 axes = (("pod", "data", "model") if multi_pod
                         else ("data", "model"))
-                mesh = _jax.make_mesh(shape, axes)
+                mesh = make_mesh(shape, axes)
                 mesh_name = ("2x" if multi_pod else "") \
                     + f"{data_w}x{model_w}"
                 spec = dataclasses.replace(

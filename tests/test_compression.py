@@ -52,7 +52,8 @@ from repro.distributed.compression import (int8_psum_mean, topk_psum_mean,
                                            compressed_mean,
                                            init_error_state)
 
-mesh = jax.make_mesh((8,), ("data",))
+mesh = jax.make_mesh((8,), ("data",),
+                     axis_types=(jax.sharding.AxisType.Auto,))
 g = jax.random.normal(jax.random.key(1), (8, 512), jnp.float32)
 ref = jnp.mean(g, axis=0)
 
