@@ -1,0 +1,76 @@
+#!/usr/bin/env python3
+"""The readings that ``check.REL_ERR_LIMIT`` is set from, on the chip.
+
+    python3 chipbench/readings.py --workload light-closed --seconds 30 \
+        --seeds 1 2 3 ... --control-seeds 101 102 103
+
+In one process: set-up as a run makes it, then for each ``--seeds`` seed a
+window of ``--seconds`` through the program, and for each
+``--control-seeds`` seed one whole pass through the control
+(``check.int8_gemm``, the reference with int8 operands in the program's
+place), each compared as a run compares.  Prints one JSON line a seed and
+a last line with the largest program reading and the smallest control
+reading.  The benchmark's runs never run this.
+"""
+
+from __future__ import annotations
+
+import argparse
+import json
+import sys
+import time
+
+import run  # noqa: F401  (puts the checkout and src/ on the path)
+
+from chipbench import catalog, check, operands, replay  # noqa: E402
+from chipbench import plan as plan_mod  # noqa: E402
+
+
+def main(argv=None) -> int:
+    p = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    p.add_argument("--workload", required=True)
+    p.add_argument("--seconds", type=float, required=True)
+    p.add_argument("--seeds", type=int, nargs="*", default=[])
+    p.add_argument("--control-seeds", type=int, nargs="*", default=[])
+    args = p.parse_args(argv)
+    cell = catalog.cell(args.workload)
+    try:
+        run.require_chips(cell.chips)
+    except run.NoChip as e:
+        print(f"readings: {e}", file=sys.stderr)
+        return 2
+    run.use_cache()
+    from repro.kernels import fused_tenant_gemm
+
+    plan, _ = plan_mod.build(cell.config, cell.traffic)
+    counter = replay.CompileCounter()
+    program, control = [], []
+    for kind, seeds in (("program", args.seeds),
+                        ("control", args.control_seeds)):
+        gemm = fused_tenant_gemm if kind == "program" else check.int8_gemm
+        for i, seed in enumerate(seeds):
+            t0 = time.perf_counter()
+            xs, ws, cut = operands.make(plan, seed)
+            if i == 0:
+                replay.warm(gemm, plan, cut, ws)
+            # the control runs one whole pass: every layer compared
+            win = replay.window(
+                gemm, plan, cut, ws, args.seconds, counter,
+                rounds=None if kind == "program" else len(plan.rounds))
+            r = check.compare(plan, *check.to_host(win.outputs, xs, ws))
+            del xs, ws, cut, win
+            (program if kind == "program" else control).append(
+                r["worst_rel_err"])
+            print(json.dumps({"kind": kind, "seed": seed, **r,
+                              "seconds": time.perf_counter() - t0}),
+                  flush=True)
+    counter.close()
+    print(json.dumps({"workload": args.workload,
+                      "program_max": max(program, default=None),
+                      "control_min": min(control, default=None),
+                      "limit": check.REL_ERR_LIMIT}))
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())
