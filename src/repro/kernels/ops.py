@@ -21,6 +21,19 @@ On top of the raw kernel this layer makes the performance decisions:
   the VMEM budget, ranks them by predicted HBM-fetch bytes per useful MAC
   (:func:`repro.kernels.partitioned_matmul.grid_accounting` is the cost
   model) and caches the winner per problem geometry.
+
+Each call marks its host phases for the profiler with
+``jax.profiler.TraceAnnotation``, on the clock of the device trace:
+``tenant_gemm.plan`` (checks, autotune, grid choice),
+``tenant_gemm.pack`` (pads, stack, concatenate; stat ``packed_bytes``, the
+bytes of the packed operands), ``tenant_gemm.plan`` again (the partition
+state sent to the device), then, in
+:mod:`repro.kernels.partitioned_matmul`, ``tenant_gemm.tables`` (the compact
+grid's index tables), ``tenant_gemm.kernel`` (stat ``grid_mode``) and
+``tenant_gemm.unpack`` (the compact mask, then the per-tenant output
+slices).  The spans never nest, so each one's duration is its own time;
+with no profiler running each costs about a microsecond.  On the device
+the kernels are named ``tenant_gemm_dense`` and ``tenant_gemm_compact``.
 """
 
 from __future__ import annotations
@@ -138,28 +151,6 @@ class FusedGemmStats:
                 **self.accounting.as_dict()}
 
 
-def record_gemm_stats(registry, stats: FusedGemmStats) -> None:
-    """Fold one fused-call :class:`FusedGemmStats` into a
-    `repro.obs` :class:`~repro.obs.registry.MetricsRegistry`.
-
-    Block/traffic accounting accumulates as ``kernel.gemm.*`` counters
-    (monotone totals across calls); the chosen block geometry lands in
-    last-write gauges and the per-call schedule efficiency in a histogram,
-    so a serving run's kernel-side dead-work fraction shows up next to the
-    scheduler metrics in one ``res.timeline.render()``."""
-    registry.counter("kernel.gemm.calls").inc()
-    registry.gauge("kernel.gemm.block_t").set(stats.block_t)
-    registry.gauge("kernel.gemm.block_k").set(stats.block_k)
-    registry.gauge("kernel.gemm.block_n").set(stats.block_n)
-    registry.histogram("kernel.gemm.schedule_efficiency").observe(
-        stats.accounting.schedule_efficiency)
-    acc = stats.accounting
-    for key in ("blocks_total", "blocks_scheduled", "blocks_live",
-                "blocks_skipped", "x_bytes_fetched", "w_bytes_fetched",
-                "out_bytes_written"):
-        registry.counter(f"kernel.gemm.{key}").inc(getattr(acc, key))
-
-
 # ---------------------------------------------------------------------------
 # fused multi-tenant GEMM
 # ---------------------------------------------------------------------------
@@ -181,54 +172,62 @@ def fused_tenant_gemm(xs: Sequence[jax.Array], ws: Sequence[jax.Array], *,
     :func:`autotune_blocks`); ``grid_mode`` is ``"dense"``, ``"compact"``
     or ``"auto"`` (compact exactly when the ragged mix leaves dead blocks).
     """
-    if len(xs) != len(ws) or not xs:
-        raise ValueError("need one (x, w) pair per tenant")
-    for i, (x, w) in enumerate(zip(xs, ws)):
-        if x.ndim != 2 or w.ndim != 2 or x.shape[1] != w.shape[0]:
-            raise ValueError(f"tenant {i}: bad shapes {x.shape} @ {w.shape}")
-    if grid_mode not in ("auto", "dense", "compact"):
-        raise ValueError(f"grid_mode must be 'auto', 'dense' or 'compact', "
-                         f"got {grid_mode!r}")
+    with jax.profiler.TraceAnnotation("tenant_gemm.plan"):
+        if len(xs) != len(ws) or not xs:
+            raise ValueError("need one (x, w) pair per tenant")
+        for i, (x, w) in enumerate(zip(xs, ws)):
+            if x.ndim != 2 or w.ndim != 2 or x.shape[1] != w.shape[0]:
+                raise ValueError(
+                    f"tenant {i}: bad shapes {x.shape} @ {w.shape}")
+        if grid_mode not in ("auto", "dense", "compact"):
+            raise ValueError(f"grid_mode must be 'auto', 'dense' or "
+                             f"'compact', got {grid_mode!r}")
 
-    shapes = tuple((int(x.shape[0]), int(x.shape[1]), int(w.shape[1]))
-                   for x, w in zip(xs, ws))
-    # mirror the kernel's operand contract: mixed x/w dtypes promote to a
-    # common type BEFORE the VMEM-budget filter and byte accounting, so the
-    # autotuner never approves blocks the promoted call would reject
-    x_dt = jnp.result_type(*(x.dtype for x in xs))
-    w_dt = jnp.result_type(*(w.dtype for w in ws))
-    if x_dt != w_dt:
-        x_dt = w_dt = jnp.promote_types(x_dt, w_dt)
-    x_dtype, w_dtype = str(x_dt), str(w_dt)
-    if block_t is None or block_k is None or block_n is None:
-        tuned = autotune_blocks(
-            shapes, x_dtype, w_dtype,
-            grid_mode="compact" if grid_mode == "auto" else grid_mode,
-            vmem_budget_bytes=vmem_budget_bytes)
-        block_t = block_t if block_t is not None else tuned[0]
-        block_k = block_k if block_k is not None else tuned[1]
-        block_n = block_n if block_n is not None else tuned[2]
+        shapes = tuple((int(x.shape[0]), int(x.shape[1]), int(w.shape[1]))
+                       for x, w in zip(xs, ws))
+        # the packed operands keep these types; the kernel promotes a mixed
+        # x/w pair to a common type, so the autotuner budgets for that type
+        # BEFORE the VMEM filter and byte accounting, and never approves
+        # blocks the promoted call would reject
+        x_dt = jnp.result_type(*(x.dtype for x in xs))
+        w_dt = jnp.result_type(*(w.dtype for w in ws))
+        x_dtype = w_dtype = str(jnp.promote_types(x_dt, w_dt))
+        if block_t is None or block_k is None or block_n is None:
+            tuned = autotune_blocks(
+                shapes, x_dtype, w_dtype,
+                grid_mode="compact" if grid_mode == "auto" else grid_mode,
+                vmem_budget_bytes=vmem_budget_bytes)
+            block_t = block_t if block_t is not None else tuned[0]
+            block_k = block_k if block_k is not None else tuned[1]
+            block_n = block_n if block_n is not None else tuned[2]
 
-    probe = None
-    if grid_mode == "auto":
-        probe = _geometry_accounting(shapes, block_t, block_k, block_n,
-                                     x_dtype, w_dtype, "dense")
-        grid_mode = ("compact" if probe.blocks_live < probe.blocks_total
-                     else "dense")
+        probe = None
+        if grid_mode == "auto":
+            probe = _geometry_accounting(shapes, block_t, block_k, block_n,
+                                         x_dtype, w_dtype, "dense")
+            grid_mode = ("compact" if probe.blocks_live < probe.blocks_total
+                         else "dense")
 
-    T = _round_up(max(x.shape[0] for x in xs), block_t)
-    K = _round_up(max(x.shape[1] for x in xs), block_k)
-    xs_pad = jnp.stack([
-        jnp.pad(x, ((0, T - x.shape[0]), (0, K - x.shape[1])))
-        for x in xs])                                     # (E, T, K)
-    w_pad = jnp.concatenate([
-        jnp.pad(w, ((0, K - w.shape[0]),
-                    (0, _round_up(w.shape[1], block_n) - w.shape[1])))
-        for w in ws], axis=1)                             # (K, N_total)
+        T = _round_up(max(t for t, _, _ in shapes), block_t)
+        K = _round_up(max(k for _, k, _ in shapes), block_k)
+        n_pad = [_round_up(n, block_n) for _, _, n in shapes]
+        packed_bytes = (len(xs) * T * K * x_dt.itemsize
+                        + K * sum(n_pad) * w_dt.itemsize)
 
-    owner = build_owner_map([w.shape[1] for w in ws], block_n)
-    valid_t = jnp.asarray([x.shape[0] for x in xs], jnp.int32)
-    valid_k = jnp.asarray([x.shape[1] for x in xs], jnp.int32)
+    with jax.profiler.TraceAnnotation("tenant_gemm.pack",
+                                      packed_bytes=packed_bytes):
+        xs_pad = jnp.stack([
+            jnp.pad(x, ((0, T - x.shape[0]), (0, K - x.shape[1])))
+            for x in xs])                                 # (E, T, K)
+        w_pad = jnp.concatenate([
+            jnp.pad(w, ((0, K - w.shape[0]), (0, n - w.shape[1])))
+            for w, n in zip(ws, n_pad)], axis=1)          # (K, N_total)
+
+    # the partition state goes to the device after the pads are dispatched
+    with jax.profiler.TraceAnnotation("tenant_gemm.plan"):
+        owner = build_owner_map([n for _, _, n in shapes], block_n)
+        valid_t = jnp.asarray([t for t, _, _ in shapes], jnp.int32)
+        valid_k = jnp.asarray([k for _, k, _ in shapes], jnp.int32)
 
     out = partitioned_matmul(xs_pad, w_pad, owner, valid_t, valid_k,
                              block_t=block_t, block_k=block_k,
@@ -236,12 +235,12 @@ def fused_tenant_gemm(xs: Sequence[jax.Array], ws: Sequence[jax.Array], *,
                              vmem_budget_bytes=vmem_budget_bytes,
                              interpret=interpret)
 
-    outs = []
-    col = 0
-    for i, w in enumerate(ws):
-        n_pad = _round_up(w.shape[1], block_n)
-        outs.append(out[:xs[i].shape[0], col:col + w.shape[1]])
-        col += n_pad
+    with jax.profiler.TraceAnnotation("tenant_gemm.unpack"):
+        outs = []
+        col = 0
+        for (t, _, n), n_cols in zip(shapes, n_pad):
+            outs.append(out[:t, col:col + n])
+            col += n_cols
     if not return_stats:
         return outs
     acc = (probe if probe is not None and grid_mode == "dense"

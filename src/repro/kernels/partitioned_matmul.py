@@ -329,6 +329,7 @@ def _dense_call(xs: jax.Array, w: jax.Array, owner: jax.Array,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="tenant_gemm_dense",
     )(owner.astype(jnp.int32), valid_t.astype(jnp.int32),
       valid_k.astype(jnp.int32), xs, w)
 
@@ -353,50 +354,56 @@ def _compact_kernel(xidx_ref, nidx_ref, tidx_ref, kidx_ref, last_ref,
         o_ref[...] = acc_ref[...].astype(o_ref.dtype)
 
 
-def _compact_call(xs: jax.Array, w: jax.Array, owner: np.ndarray,
-                  valid_t: np.ndarray, valid_k: np.ndarray, *,
+def _compact_call(xs: jax.Array, w: jax.Array, owner, valid_t, valid_k, *,
                   block_t: int, block_k: int, block_n: int,
                   interpret: bool) -> jax.Array:
     E, T, K = xs.shape
     _, N = w.shape
-    tl, kl = _live_extents(np.asarray(owner, np.int64),
-                           np.asarray(valid_t, np.int64),
-                           np.asarray(valid_k, np.int64),
-                           T=T, K=K, block_t=block_t, block_k=block_k)
-    nidx, tidx, kidx, last = _tables_from_extents(tl, kl)
-    if nidx.size == 0:  # nothing live: the contract output is all zeros
-        return jnp.zeros((T, N), jnp.float32)
-    xidx = np.asarray(owner, np.int32)[nidx]
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=5,
-        grid=(int(nidx.size),),
-        in_specs=[
-            pl.BlockSpec((1, block_t, block_k),
-                         lambda i, xi, ni, ti, ki, la: (xi[i], ti[i], ki[i])),
-            pl.BlockSpec((block_k, block_n),
-                         lambda i, xi, ni, ti, ki, la: (ki[i], ni[i])),
-        ],
-        out_specs=pl.BlockSpec((block_t, block_n),
-                               lambda i, xi, ni, ti, ki, la: (ti[i], ni[i])),
-        scratch_shapes=[pltpu.VMEM((block_t, block_n), jnp.float32)],
-    )
-    out = pl.pallas_call(
-        _compact_kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((T, N), jnp.float32),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary",)),
-        interpret=interpret,
-    )(jnp.asarray(xidx), jnp.asarray(nidx), jnp.asarray(tidx),
-      jnp.asarray(kidx), jnp.asarray(last), xs, w)
+    with jax.profiler.TraceAnnotation("tenant_gemm.tables"):
+        owner = np.asarray(owner, np.int64)
+        tl, kl = _live_extents(owner, np.asarray(valid_t, np.int64),
+                               np.asarray(valid_k, np.int64),
+                               T=T, K=K, block_t=block_t, block_k=block_k)
+        nidx, tidx, kidx, last = _tables_from_extents(tl, kl)
+        if nidx.size == 0:  # nothing live: the contract output is all zeros
+            return jnp.zeros((T, N), jnp.float32)
+        tables = [jnp.asarray(a) for a in
+                  (owner[nidx].astype(np.int32), nidx, tidx, kidx, last)]
+    with jax.profiler.TraceAnnotation("tenant_gemm.kernel",
+                                      grid_mode="compact"):
+        grid_spec = pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=5,
+            grid=(int(nidx.size),),
+            in_specs=[
+                pl.BlockSpec((1, block_t, block_k),
+                             lambda i, xi, ni, ti, ki, la:
+                             (xi[i], ti[i], ki[i])),
+                pl.BlockSpec((block_k, block_n),
+                             lambda i, xi, ni, ti, ki, la: (ki[i], ni[i])),
+            ],
+            out_specs=pl.BlockSpec((block_t, block_n),
+                                   lambda i, xi, ni, ti, ki, la:
+                                   (ti[i], ni[i])),
+            scratch_shapes=[pltpu.VMEM((block_t, block_n), jnp.float32)],
+        )
+        out = pl.pallas_call(
+            _compact_kernel,
+            grid_spec=grid_spec,
+            out_shape=jax.ShapeDtypeStruct((T, N), jnp.float32),
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("arbitrary",)),
+            interpret=interpret,
+            name="tenant_gemm_compact",
+        )(*tables, xs, w)
     # Tiles with no live block are never visited (never drained), so their
     # VMEM-backed output is unspecified; the contract says they are zero.
     # One host-side mask restores it — still no grid steps, no fetches.
-    live_rows = np.repeat(tl * block_t, block_n)               # (N,)
-    if (live_rows >= T).all():
-        return out
-    mask = np.arange(T)[:, None] < live_rows[None, :]
-    return jnp.where(jnp.asarray(mask), out, 0.0)
+    with jax.profiler.TraceAnnotation("tenant_gemm.unpack"):
+        live_rows = np.repeat(tl * block_t, block_n)           # (N,)
+        if (live_rows >= T).all():
+            return out
+        mask = np.arange(T)[:, None] < live_rows[None, :]
+        return jnp.where(jnp.asarray(mask), out, 0.0)
 
 
 # ---------------------------------------------------------------------------
@@ -458,15 +465,16 @@ def partitioned_matmul(xs: jax.Array, w: jax.Array, owner: jax.Array,
         raise ValueError(f"grid_mode must be one of {GRID_MODES}, "
                          f"got {grid_mode!r}")
     if grid_mode == "dense":
-        return _dense_call(xs, w, owner, valid_t, valid_k,
-                           block_t=block_t, block_k=block_k,
-                           block_n=block_n, interpret=interpret)
+        with jax.profiler.TraceAnnotation("tenant_gemm.kernel",
+                                          grid_mode="dense"):
+            return _dense_call(xs, w, owner, valid_t, valid_k,
+                               block_t=block_t, block_k=block_k,
+                               block_n=block_n, interpret=interpret)
     if any(isinstance(a, jax.core.Tracer) for a in (owner, valid_t, valid_k)):
         raise ValueError(
             "grid_mode='compact' builds host-side index tables from the "
             "partition state, so owner/valid_t/valid_k must be concrete "
             "arrays — call it outside jit (or use grid_mode='dense')")
-    return _compact_call(xs, w, np.asarray(owner), np.asarray(valid_t),
-                         np.asarray(valid_k), block_t=block_t,
+    return _compact_call(xs, w, owner, valid_t, valid_k, block_t=block_t,
                          block_k=block_k, block_n=block_n,
                          interpret=interpret)

@@ -91,6 +91,14 @@ def test_six_tenant_heavy_round_compiles(one_chip, heavy_rounds, grid_mode):
     assert compiled.memory_analysis().argument_size_in_bytes > 0
 
 
+@pytest.mark.parametrize("grid_mode", ["dense", "compact"])
+def test_kernel_keeps_its_name_in_the_compiled_program(one_chip, grid_mode):
+    # the device trace finds each kernel by this name
+    gemms = [(200, 300, 256), (40, 60, 384)]
+    compiled = _compile(one_chip, gemms, jnp.bfloat16, grid_mode=grid_mode)
+    assert f"tenant_gemm_{grid_mode}" in compiled.as_text()
+
+
 def test_alexnet_fc_compiles(one_chip):
     gemms = [chip_smoke.FC_GEMM]
     compiled = _compile(one_chip, gemms, jnp.bfloat16, grid_mode="dense")

@@ -1,5 +1,7 @@
 """Pallas partitioned-WS GEMM vs the pure-jnp oracle (interpret mode)."""
 
+import glob
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -350,6 +352,61 @@ class TestFusedTenantGemm:
         with pytest.raises(ValueError):
             fused_tenant_gemm([jnp.zeros((4, 8))], [jnp.zeros((9, 4))],
                               interpret=True)
+
+    @pytest.mark.parametrize("grid_mode, spans", [
+        ("dense", {"plan": 2, "pack": 1, "kernel": 1, "unpack": 1}),
+        ("compact", {"plan": 2, "pack": 1, "tables": 1, "kernel": 1,
+                     "unpack": 2}),
+    ])
+    def test_host_phases_are_spans_on_the_profiler(self, tmp_path,
+                                                   grid_mode, spans):
+        """Each phase of one call is a flat span in the profiler's trace
+        (``plan`` twice, around packing; ``unpack`` in both layers of the
+        compact grid), and ``packed_bytes`` counts the padded operands the
+        kernel gets."""
+        key = jax.random.key(3)
+        shapes = [(150, 130, 100), (40, 60, 200)]
+        xs = [jax.random.normal(jax.random.fold_in(key, i), (t, k),
+                                jnp.bfloat16)
+              for i, (t, k, _) in enumerate(shapes)]
+        ws = [jax.random.normal(jax.random.fold_in(key, 10 + i), (k, n),
+                                jnp.float32)
+              for i, (_, k, n) in enumerate(shapes)]
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            outs = fused_tenant_gemm(xs, ws, grid_mode=grid_mode,
+                                     interpret=True)
+            jax.block_until_ready(outs)
+        finally:
+            jax.profiler.stop_trace()
+        path = glob.glob(str(tmp_path / "**" / "*.xplane.pb"),
+                         recursive=True)
+        data = jax.profiler.ProfileData.from_file(path[0])
+        found = [(e.start_ns, e.start_ns + e.duration_ns, e.name,
+                  dict(e.stats))
+                 for plane in data.planes for line in plane.lines
+                 for e in line.events if e.name.startswith("tenant_gemm.")]
+        counts = {}
+        for _, _, name, _ in found:
+            phase = name.removeprefix("tenant_gemm.")
+            counts[phase] = counts.get(phase, 0) + 1
+        assert counts == spans
+        found.sort()
+        assert all(a[1] <= b[0] for a, b in zip(found, found[1:])), \
+            "spans overlap"
+        stats = {name: st for _, _, name, st in found}
+        assert stats["tenant_gemm.kernel"]["grid_mode"] == grid_mode
+        bt, bk, bn = autotune_blocks(tuple(shapes), "float32", "float32",
+                                     grid_mode=grid_mode)
+        T = -(-max(t for t, _, _ in shapes) // bt) * bt
+        K = -(-max(k for _, k, _ in shapes) // bk) * bk
+        N = sum(-(-n // bn) * bn for _, _, n in shapes)
+        xs_pad = jnp.zeros((len(shapes), T, K), jnp.bfloat16)
+        w_pad = jnp.zeros((K, N), jnp.float32)
+        assert stats["tenant_gemm.pack"]["packed_bytes"] == \
+            xs_pad.nbytes + w_pad.nbytes
+        for (t, _, n), o in zip(shapes, outs):
+            assert o.shape == (t, n)
 
 
 class TestKernelAlgorithmIntegration:
