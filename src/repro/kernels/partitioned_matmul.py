@@ -41,9 +41,11 @@ compact mode the live-block index tables) are the dynamic partition state:
 Algorithm 1 re-computes them per scheduling round on the host, and the SAME
 compiled kernel serves any partition layout of the same geometry — that is
 what makes the partitioning *dynamic* at zero recompile cost.  (The compact
-grid's *length* is the live-block count, so layouts with different padding
-compile separate grids; :func:`repro.kernels.ops.fused_tenant_gemm` weighs
-that trade when ``grid_mode="auto"``.)
+grid's *length* is the live-block count: each distinct count, with the
+operands' shapes and the blocks, is compiled once and then reused from the
+jit cache with any tables of that length; a layout with a new count
+compiles a grid of its own.  :func:`repro.kernels.ops.fused_tenant_gemm`
+weighs that trade when ``grid_mode="auto"``.)
 """
 
 from __future__ import annotations
@@ -354,6 +356,47 @@ def _compact_kernel(xidx_ref, nidx_ref, tidx_ref, kidx_ref, last_ref,
         o_ref[...] = acc_ref[...].astype(o_ref.dtype)
 
 
+@functools.partial(
+    jax.jit,
+    static_argnames=("block_t", "block_k", "block_n", "interpret"))
+def _compact_grid(xidx: jax.Array, nidx: jax.Array, tidx: jax.Array,
+                  kidx: jax.Array, last: jax.Array, xs: jax.Array,
+                  w: jax.Array, *, block_t: int, block_k: int, block_n: int,
+                  interpret: bool) -> jax.Array:
+    """The compact grid over its live-step tables.
+
+    The grid's length is the tables' length, a shape, so it keys the jit
+    cache with the operands' shapes and the blocks; the tables' contents
+    are run-time operands.  A layout of a known length dispatches the
+    cached program with its own tables.
+    """
+    T, N = xs.shape[1], w.shape[1]
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=5,
+        grid=(nidx.shape[0],),
+        in_specs=[
+            pl.BlockSpec((1, block_t, block_k),
+                         lambda i, xi, ni, ti, ki, la:
+                         (xi[i], ti[i], ki[i])),
+            pl.BlockSpec((block_k, block_n),
+                         lambda i, xi, ni, ti, ki, la: (ki[i], ni[i])),
+        ],
+        out_specs=pl.BlockSpec((block_t, block_n),
+                               lambda i, xi, ni, ti, ki, la:
+                               (ti[i], ni[i])),
+        scratch_shapes=[pltpu.VMEM((block_t, block_n), jnp.float32)],
+    )
+    return pl.pallas_call(
+        _compact_kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((T, N), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+        name="tenant_gemm_compact",
+    )(xidx, nidx, tidx, kidx, last, xs, w)
+
+
 def _compact_call(xs: jax.Array, w: jax.Array, owner, valid_t, valid_k, *,
                   block_t: int, block_k: int, block_n: int,
                   interpret: bool) -> jax.Array:
@@ -371,30 +414,9 @@ def _compact_call(xs: jax.Array, w: jax.Array, owner, valid_t, valid_k, *,
                   (owner[nidx].astype(np.int32), nidx, tidx, kidx, last)]
     with jax.profiler.TraceAnnotation("tenant_gemm.kernel",
                                       grid_mode="compact"):
-        grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=5,
-            grid=(int(nidx.size),),
-            in_specs=[
-                pl.BlockSpec((1, block_t, block_k),
-                             lambda i, xi, ni, ti, ki, la:
-                             (xi[i], ti[i], ki[i])),
-                pl.BlockSpec((block_k, block_n),
-                             lambda i, xi, ni, ti, ki, la: (ki[i], ni[i])),
-            ],
-            out_specs=pl.BlockSpec((block_t, block_n),
-                                   lambda i, xi, ni, ti, ki, la:
-                                   (ti[i], ni[i])),
-            scratch_shapes=[pltpu.VMEM((block_t, block_n), jnp.float32)],
-        )
-        out = pl.pallas_call(
-            _compact_kernel,
-            grid_spec=grid_spec,
-            out_shape=jax.ShapeDtypeStruct((T, N), jnp.float32),
-            compiler_params=pltpu.CompilerParams(
-                dimension_semantics=("arbitrary",)),
-            interpret=interpret,
-            name="tenant_gemm_compact",
-        )(*tables, xs, w)
+        out = _compact_grid(*tables, xs, w, block_t=block_t,
+                            block_k=block_k, block_n=block_n,
+                            interpret=interpret)
     # Tiles with no live block are never visited (never drained), so their
     # VMEM-backed output is unspecified; the contract says they are zero.
     # One host-side mask restores it — still no grid steps, no fetches.

@@ -1,5 +1,6 @@
 """Pallas partitioned-WS GEMM vs the pure-jnp oracle (interpret mode)."""
 
+import contextlib
 import glob
 
 import jax
@@ -114,6 +115,22 @@ def _mk_int(seed, E, T, K, N, n_blocks, valid_t, valid_k):
             jnp.asarray(valid_t, jnp.int32), jnp.asarray(valid_k, jnp.int32))
 
 
+@contextlib.contextmanager
+def _counting_lowerings():
+    """Collect JAX's lowerings to MLIR while the block runs."""
+    events = []
+
+    def listen(event, _secs, **_):
+        if event == "/jax/core/compile/jaxpr_to_mlir_module_duration":
+            events.append(event)
+
+    jax.monitoring.register_event_duration_secs_listener(listen)
+    try:
+        yield events
+    finally:
+        jax.monitoring.unregister_event_duration_listener(listen)
+
+
 class TestCompactGrid:
     """grid_mode='compact' — live blocks only, same numerics as dense."""
 
@@ -196,6 +213,49 @@ class TestCompactGrid:
 
         with pytest.raises(ValueError, match="concrete"):
             f(jnp.zeros((1,), jnp.int32), jnp.array([128], jnp.int32))
+
+    def test_repeated_compact_call_lowers_nothing(self):
+        key = jax.random.key(11)
+        xs = [jax.random.normal(jax.random.fold_in(key, i), (t, k))
+              for i, (t, k) in enumerate([(256, 256), (40, 60)])]
+        ws = [jax.random.normal(jax.random.fold_in(key, 10 + i), (k, 128))
+              for i, k in enumerate([256, 60])]
+        kw = dict(block_t=128, block_k=128, block_n=128, interpret=True,
+                  return_stats=True)
+        first, stats = fused_tenant_gemm(xs, ws, **kw)
+        assert stats.grid_mode == "compact"
+        with _counting_lowerings() as lowerings:
+            second, _ = fused_tenant_gemm(xs, ws, **kw)
+            jax.block_until_ready(second)
+        assert lowerings == []
+        for a, b in zip(first, second):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+    def test_cached_grid_takes_its_tables_at_run_time(self):
+        """Layouts of one live-step count share a program and each gets
+        its own tables; a new count compiles a grid of its own."""
+        B, T, K, N = 64, 128, 128, 128
+        owner = jnp.array([0, 1], jnp.int32)
+        kw = dict(block_t=B, block_k=B, block_n=B, grid_mode="compact",
+                  interpret=True)
+
+        def run(valid_t):
+            xs, w, _, vt, vk = _mk_int(sum(valid_t), 2, T, K, N, 2,
+                                       valid_t, [K, K])
+            out = partitioned_matmul(xs, w, owner, vt, vk, **kw)
+            ref = partitioned_matmul_ref(xs, w, owner, vt, B)
+            np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
+
+        # the short tenant swaps: 2·2 + 1·2 live steps either way
+        assert (live_block_tables([0, 1], [128, 30], [K, K], T=T, K=K,
+                                  block_t=B, block_k=B)[0].size ==
+                live_block_tables([0, 1], [30, 128], [K, K], T=T, K=K,
+                                  block_t=B, block_k=B)[0].size == 6)
+        run([128, 30])
+        with _counting_lowerings() as lowerings:
+            run([30, 128])
+        assert lowerings == []
+        run([128, 128])  # 8 live steps: a grid of another length
 
     def test_bad_grid_mode_rejected(self):
         xs = jnp.zeros((1, 128, 128), jnp.float32)
