@@ -1,5 +1,6 @@
-"""Whole-pass share of the chip's bf16 peak: the useful GEMM operations
-completed in the traced window over the window's seconds times the peak."""
+"""Whole-pass share of the chip's bf16 peak: the useful operations, as the
+layers' kinds count them, completed in the traced window over the window's
+seconds times the peak."""
 
 
 def read(ctx):

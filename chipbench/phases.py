@@ -183,31 +183,30 @@ def per_round_ms(ctx, *names: str) -> float | None:
     return sum(hit) / rounds * 1e3 if hit and rounds else None
 
 
-def measure(cell: catalog.Cell, seed: int, seconds: float, *, gemm=None,
+def measure(cell: catalog.Cell, seed: int, seconds: float, *, given=None,
             plan=None, device=None) -> dict:
     """Set up and trace one window of the cell, then check it, as
-    ``run.run_cell`` does with a trace; ``gemm``, ``plan`` and ``device`` as
-    there.  The result object of ``main``."""
+    ``run.run_cell`` does with a trace; ``given``, ``plan`` and ``device``
+    as there.  The result object of ``main``."""
     import jax
     from chipbench import check, operands, replay
     from chipbench import plan as plan_mod
 
     t0 = time.perf_counter()
     device = device or jax.devices()[0]
-    if gemm is None:
-        from repro.kernels import fused_tenant_gemm as gemm
     if plan is None:
         plan, _ = plan_mod.build(cell.config, cell.traffic)
     xs, ws, cut = operands.make(plan, seed)
+    calls = replay.calls(plan, replay.entries(plan, given), cut, ws)
     counter = replay.CompileCounter()
     tmp = tempfile.mkdtemp(prefix="chipbench-phases-")
     try:
         with counter.counting():
-            replay.warm(gemm, plan, cut, ws)
+            replay.warm(calls)
         setup_s = time.perf_counter() - t0
         jax.profiler.start_trace(tmp)
         try:
-            win = replay.window(gemm, plan, cut, ws, seconds, counter)
+            win = replay.window(plan, calls, seconds, counter)
         finally:
             jax.profiler.stop_trace()
         t1 = time.perf_counter()
@@ -222,6 +221,7 @@ def measure(cell: catalog.Cell, seed: int, seconds: float, *, gemm=None,
 
     host = check.to_host(win.outputs, xs, ws)
     win.outputs = None
+    del xs, ws, cut, calls
     readings = check.compare(plan, *host)
 
     peaks = catalog.peaks(device.device_kind) if device.platform == "tpu" \

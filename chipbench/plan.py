@@ -8,8 +8,10 @@ layer live at ``s`` streams its rows ``[row(s), row(s'))`` with
 ``row(s) = floor(T * (s - a) / (b - a))``, so a layer's weights stay while
 its activations stream through the rounds it spans (the paper's
 weight-stationary dataflow, in time).  The rows of consecutive rounds meet,
-so over a pass every layer's ``(T, N)`` output is computed once and only
-once.  A round's tenants are ordered by the first array column they own.
+so over a pass every layer's output rows are computed once and only once.
+A round's tenants are ordered by the first array column they own.  What a
+layer's rows are, and what a slice of them computes, its kind says
+(``chipbench.kinds``).
 """
 
 from __future__ import annotations
@@ -17,18 +19,22 @@ from __future__ import annotations
 import dataclasses
 import math
 import time
-from typing import Sequence
+from types import ModuleType
+from typing import Any, Sequence
+
+from chipbench import kinds
 
 
 @dataclasses.dataclass(frozen=True)
 class Layer:
-    """One layer GEMM ``(t, k) @ (k, n)`` of one tenant."""
+    """One layer of one tenant: ``rows`` streamed rows, and the rest as its
+    kind (a module of ``chipbench.kinds``) reads it from ``spec``."""
 
     tenant: str
     name: str
-    t: int
-    k: int
-    n: int
+    kind: ModuleType
+    rows: int
+    spec: Any
 
 
 @dataclasses.dataclass(frozen=True)
@@ -49,16 +55,18 @@ class Plan:
     layers: tuple[Layer, ...]
     rounds: tuple[tuple[Slice, ...], ...]
 
-    def shapes(self, rnd: Sequence[Slice]) -> tuple[tuple[int, int, int], ...]:
-        """The round's GEMMs as ``(rows, k, n)`` per tenant, in call order."""
-        return tuple((s.rows, self.layers[s.layer].k, self.layers[s.layer].n)
-                     for s in rnd)
+
+def layer(tenant: str, row) -> Layer:
+    """A configuration row of ``tenant`` as a layer of the kind it names."""
+    kind = kinds.of_row(row)
+    name, rows, spec = kind.parse(row)
+    return Layer(tenant, name, kind, rows, spec)
 
 
 def config_layers(config: dict) -> tuple[Layer, ...]:
     """Every layer the configuration file lists, tenant by tenant."""
-    return tuple(Layer(t["model"], name, m, k, n)
-                 for t in config["tenants"] for name, m, k, n in t["layers"])
+    return tuple(layer(t["model"], row)
+                 for t in config["tenants"] for row in t["layers"])
 
 
 def row_at(s: float, a: float, b: float, t: int) -> int:
@@ -99,7 +107,7 @@ def rounds_from_trace(trace, index: dict[tuple[str, int], int],
         rnd = []
         for e in live:
             li = index[e.tenant, e.layer_index]
-            t = layers[li].t
+            t = layers[li].rows
             a, b = e.compute_start, e.compute_end
             r0, r1 = row_at(s, a, b, t), row_at(s2, a, b, t)
             if r1 > r0:
@@ -112,18 +120,20 @@ def rounds_from_trace(trace, index: dict[tuple[str, int], int],
 def _dnngs(config: dict, stagger_s: float):
     """The configuration's tenants as the program's DNNGs, staggered.
 
-    Raises when the program's layer GEMMs differ from the configuration
-    file, so a change to ``repro.sim.workloads`` cannot change the work.
+    Raises when the program's layers differ from the configuration file's
+    rows, as each row's kind reads them, so a change to
+    ``repro.sim.workloads`` cannot change the work.
     """
     from repro.sim.workloads import MODELS
 
     dnngs = []
     for i, t in enumerate(config["tenants"]):
         g = MODELS[t["model"]]()
-        got = [[layer.name, layer.gemm_m, layer.gemm_k, layer.gemm_n]
-               for layer in g.layers]
-        if got != t["layers"]:
-            raise ValueError(f"{t['model']}: the program's layer GEMMs "
+        rows = t["layers"]
+        if len(g.layers) != len(rows) or not all(
+                kinds.of_row(row).matches(row, la)
+                for row, la in zip(rows, g.layers)):
+            raise ValueError(f"{t['model']}: the program's layers "
                              f"differ from configuration {config['name']}")
         dnngs.append(dataclasses.replace(g, arrival_time=i * stagger_s))
     return dnngs

@@ -1,10 +1,12 @@
 """Warm-up and the measured window: the plan's rounds, back to back.
 
-Each round is one call of the program's ``fused_tenant_gemm`` on its
-tenants' activation row slices and whole weights, timed from the call to
-all its outputs ready.  Passes follow one another until the window's
-seconds are up; the round under way then finishes, and the window ends
-with it.
+Set-up turns each round into its calls: the slices whose kinds name the
+same program entry share one call of it (for every GEMM, the program's
+``fused_tenant_gemm`` on the tenants' activation row slices and whole
+weights), in the order of their first array column.  A round is timed from
+its first call to all its calls' outputs ready.  Passes follow one another
+until the window's seconds are up; the round under way then finishes, and
+the window ends with it.
 """
 
 from __future__ import annotations
@@ -73,24 +75,70 @@ class Window:
     lowerings: int
     compile_requests: int
     cache_misses: int
-    # layer -> [(rows, output)] of its slices as last computed
+    # layer -> [(rows, its pieces' outputs)] of its slices as last computed
     outputs: dict
 
 
-def _call(gemm, plan: Plan, cut, ws, r: int):
-    rnd = plan.rounds[r]
-    return gemm(cut[r], [ws[s.layer] for s in rnd])
+@dataclasses.dataclass(frozen=True)
+class Round:
+    """A round's calls, each ``(entry, arguments)``, and for each of its
+    slices where its outputs come back: ``(call, first piece, pieces)``."""
+
+    calls: tuple
+    slots: tuple
+
+    def signature(self) -> tuple:
+        """The calls' entries and argument shapes and types, which fix every
+        program the round compiles."""
+        return tuple((fn, tuple((a.shape, a.dtype) for col in args
+                                for a in col)) for fn, args in self.calls)
 
 
-def warm(gemm, plan: Plan, cut, ws) -> int:
-    """Run one round of each distinct shape signature, one after another;
-    the count of rounds run."""
-    first: dict[tuple, int] = {}
+def entries(plan: Plan, given: dict | None = None) -> dict:
+    """The callable of each program entry the plan's kinds name, by name:
+    the program's own, where ``given`` names none in its place."""
+    out = dict(given or {})
+    for layer in plan.layers:
+        if layer.kind.ENTRY not in out:
+            out[layer.kind.ENTRY] = layer.kind.program()
+    return out
+
+
+def calls(plan: Plan, fns: dict, cut, ws) -> tuple[Round, ...]:
+    """Every round's calls, built in set-up from the cut activations and the
+    weights; ``fns`` maps an entry's name to the callable that runs it."""
+    out = []
     for r, rnd in enumerate(plan.rounds):
-        first.setdefault(plan.shapes(rnd), r)
+        pieces: dict[str, list] = {}
+        slots = []
+        for j, s in enumerate(rnd):
+            layer = plan.layers[s.layer]
+            got = pieces.setdefault(layer.kind.ENTRY, [])
+            new = layer.kind.pieces(layer, s.row0, s.row1, cut[r][j],
+                                     ws[s.layer])
+            slots.append((list(pieces).index(layer.kind.ENTRY), len(got),
+                          len(new)))
+            got.extend(new)
+        out.append(Round(
+            calls=tuple((fns[name], [list(col) for col in zip(*p)])
+                        for name, p in pieces.items()),
+            slots=tuple(slots)))
+    return tuple(out)
+
+
+def _launch(rnd: Round) -> list:
+    return [fn(*args) for fn, args in rnd.calls]
+
+
+def warm(rounds: tuple[Round, ...]) -> int:
+    """Run one round of each distinct signature, one after another; the
+    count of rounds run."""
+    first: dict[tuple, Round] = {}
+    for rnd in rounds:
+        first.setdefault(rnd.signature(), rnd)
     t0 = time.perf_counter()
-    for done, r in enumerate(first.values(), 1):
-        jax.block_until_ready(_call(gemm, plan, cut, ws, r))
+    for done, rnd in enumerate(first.values(), 1):
+        jax.block_until_ready(_launch(rnd))
         if done % 50 == 0:
             print(f"warm-up: {done}/{len(first)} round shapes, "
                   f"{time.perf_counter() - t0:.1f} s", file=sys.stderr,
@@ -98,14 +146,14 @@ def warm(gemm, plan: Plan, cut, ws) -> int:
     return len(first)
 
 
-def window(gemm, plan: Plan, cut, ws, seconds: float,
+def window(plan: Plan, calls: tuple[Round, ...], seconds: float,
            counter: CompileCounter, rounds: int | None = None) -> Window:
-    """Replay passes of the plan for ``seconds``, or for exactly ``rounds``
-    rounds where that is given; keep each slice's output."""
-    round_flops = [work.flops(plan.shapes(rnd)) for rnd in plan.rounds]
+    """Replay passes of the plan's ``calls`` for ``seconds``, or for exactly
+    ``rounds`` rounds where that is given; keep each slice's output."""
+    round_flops = [work.flops(plan, rnd) for rnd in plan.rounds]
     pass_flops = sum(round_flops)
     n = len(plan.rounds)
-    latest: dict[tuple[int, int], object] = {}
+    latest: dict[int, list] = {}
     ids, lat, launch = [], [], []
     flops = 0
     before = counter.counts()
@@ -118,7 +166,7 @@ def window(gemm, plan: Plan, cut, ws, seconds: float,
             r = i % n
             t0 = time.perf_counter()
             with jax.profiler.TraceAnnotation("launch", round=r):
-                outs = _call(gemm, plan, cut, ws, r)
+                outs = _launch(calls[r])
             t1 = time.perf_counter()
             with jax.profiler.TraceAnnotation("wait", round=r):
                 jax.block_until_ready(outs)
@@ -127,9 +175,7 @@ def window(gemm, plan: Plan, cut, ws, seconds: float,
             launch.append(t1 - t0)
             lat.append(t2 - t0)
             flops += round_flops[r]
-            outs = list(outs)
-            for j in range(len(plan.rounds[r])):
-                latest[r, j] = outs[j] if j < len(outs) else None
+            latest[r] = outs
             i += 1
         t_end = time.perf_counter()
     rounds_done = len(ids)
@@ -139,16 +185,27 @@ def window(gemm, plan: Plan, cut, ws, seconds: float,
                   lowerings=counter.lowerings - before[0],
                   compile_requests=counter.requests - before[1],
                   cache_misses=counter.misses - before[2],
-                  outputs=_layer_outputs(plan, latest))
+                  outputs=_layer_outputs(plan, calls, latest))
 
 
-def _layer_outputs(plan: Plan, latest: dict) -> dict:
-    """Each layer all of whose slices were launched: its slices' outputs,
-    in the order the pass computes them."""
+def _slice_outputs(outs: list, slot: tuple) -> list | None:
+    """A slice's pieces' outputs from its round's call outputs, or None
+    where its call returned fewer."""
+    call, first, count = slot
+    got = list(outs[call])[first:first + count]
+    return got if len(got) == count else None
+
+
+def _layer_outputs(plan: Plan, calls: tuple[Round, ...], latest: dict
+                   ) -> dict:
+    """Each layer all of whose slices were launched: per slice its rows and
+    its pieces' outputs, in the order the pass computes them."""
     parts: dict[int, list] = {}
     launched: dict[int, bool] = {}
     for r, rnd in enumerate(plan.rounds):
         for j, s in enumerate(rnd):
-            launched[s.layer] = launched.get(s.layer, True) and (r, j) in latest
-            parts.setdefault(s.layer, []).append((s.rows, latest.get((r, j))))
+            launched[s.layer] = launched.get(s.layer, True) and r in latest
+            out = _slice_outputs(latest[r], calls[r].slots[j]) \
+                if r in latest else None
+            parts.setdefault(s.layer, []).append((s.rows, out))
     return {li: parts[li] for li in sorted(parts) if launched[li]}

@@ -8,9 +8,10 @@
 From the root of a checkout.  Set-up schedules the cell's tenants through
 ``repro.api.Session``, makes the operands on the device from the seed and
 warms every round shape; the window then replays the schedule's
-exactly-once rounds through ``repro.kernels.fused_tenant_gemm`` for
-``--seconds``; afterwards each layer computed in the window is compared with
-a float32 reference.  ``--trace 1`` records a profiler trace of the window
+exactly-once rounds through the program entries the layers' kinds name
+(``repro.kernels.fused_tenant_gemm`` for a GEMM) for ``--seconds``;
+afterwards each layer computed in the window is compared with its kind's
+float32 reference.  ``--trace 1`` records a profiler trace of the window
 and reports the per-layer metrics instead of the end-to-end ones.
 
 Exits non-zero, printing no result, unless JAX's devices are TPUs and as
@@ -89,12 +90,13 @@ def _trace_window(fn, enabled: bool):
 
 
 def run_cell(cell: catalog.Cell, seed: int, seconds: float, trace: bool, *,
-             gemm=None, plan=None, device=None, t_setup0=None) -> dict:
+             given=None, plan=None, device=None, t_setup0=None) -> dict:
     """Set up, measure and check one run; return the result object.
 
-    ``gemm`` defaults to the program's ``fused_tenant_gemm`` and ``plan`` to
-    the cell's schedule; tests pass others.  ``device`` is the chip checked
-    by ``require_chips`` (None runs on JAX's first device).
+    ``plan`` defaults to the cell's schedule, and each entry to the
+    program's own; tests pass another plan, and callables in ``given`` (a
+    dict by entry name) in the program's place.  ``device`` is the chip
+    checked by ``require_chips`` (None runs on JAX's first device).
     """
     import jax
     from chipbench import check, operands, replay
@@ -102,8 +104,6 @@ def run_cell(cell: catalog.Cell, seed: int, seconds: float, trace: bool, *,
 
     t_setup0 = time.perf_counter() if t_setup0 is None else t_setup0
     device = device or jax.devices()[0]
-    if gemm is None:
-        from repro.kernels import fused_tenant_gemm as gemm
     peaks = catalog.peaks(device.device_kind) if device.platform == "tpu" \
         else None
     if plan is None:
@@ -111,15 +111,15 @@ def run_cell(cell: catalog.Cell, seed: int, seconds: float, trace: bool, *,
     else:
         schedule_s = 0.0
     xs, ws, cut = operands.make(plan, seed)
+    calls = replay.calls(plan, replay.entries(plan, given), cut, ws)
     counter = replay.CompileCounter()
     try:
         with counter.counting():
-            warmed = replay.warm(gemm, plan, cut, ws)
+            warmed = replay.warm(calls)
         warm_counts = counter.counts()
         setup_s = time.perf_counter() - t_setup0
         win, red = _trace_window(
-            lambda: replay.window(gemm, plan, cut, ws, seconds, counter),
-            trace)
+            lambda: replay.window(plan, calls, seconds, counter), trace)
     finally:
         counter.close()
     stats = device.memory_stats() or {}
@@ -129,7 +129,7 @@ def run_cell(cell: catalog.Cell, seed: int, seconds: float, trace: bool, *,
     # device's arrays freed, then the reference layer by layer
     host = check.to_host(win.outputs, xs, ws)
     win.outputs = None
-    del xs, ws, cut
+    del xs, ws, cut, calls
     readings = check.compare(plan, *host)
     del host
 

@@ -5,8 +5,9 @@
 They cover the round plan, the operation and byte count, the trace
 reduction, the refusal to run without a TPU, a rehearsal of the harness in
 interpret mode with the faults its check has to catch and the int8 control,
-and an ahead-of-time compile of the largest ``heavy-closed`` rounds for a
-described TPU v5e.
+the seam that lets a layer kind of another shape or program entry join a
+round (with two kinds that live beside these tests), and an ahead-of-time
+compile of the largest ``heavy-closed`` rounds for a described TPU v5e.
 """
 
 from __future__ import annotations
@@ -14,6 +15,7 @@ from __future__ import annotations
 import dataclasses
 import functools
 import glob
+import hashlib
 import json
 import os
 import subprocess
@@ -26,7 +28,10 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from chipbench import catalog, check, devtrace, operands, plan, run, work
+from chipbench import (catalog, devtrace, kinds, operands, plan, replay,
+                       run, work)
+from chipbench.kinds import gemm
+from chipbench.tests import kind_attention, kind_experts
 
 ROOT = Path(__file__).resolve().parents[2]
 # the Table-1 mixes as (configuration, traffic), with (rounds a pass,
@@ -35,6 +40,8 @@ ROOT = Path(__file__).resolve().parents[2]
 MIXES = {"heavy-closed": ("table1-heavy", "closed-equal", 437, 1050),
          "light-closed": ("table1-light", "closed-equal", 42, 67),
          "heavy-solo": ("table1-heavy", "closed-solo", 237, 237)}
+# round signatures warm-up runs (PERF.md, Cells)
+WARMED = {"heavy-closed": 424, "light-closed": 30, "heavy-solo": 100}
 
 
 def _load(kind: str, name: str) -> dict:
@@ -64,11 +71,22 @@ def test_row_split_covers_every_layer_once(plans, name):
     assert sorted(rows) == list(range(len(p.layers)))
     for li, spans in rows.items():
         # rounds run in time order, so a layer's slices come in row order
-        assert spans[0][0] == 0 and spans[-1][1] == p.layers[li].t
+        assert spans[0][0] == 0 and spans[-1][1] == p.layers[li].rows
         assert all(a[1] == b[0] for a, b in zip(spans, spans[1:]))
     assert (len(p.rounds), sum(map(len, p.rounds))) == MIXES[name][2:]
-    useful = sum(2 * la.t * la.k * la.n for la in p.layers)
-    assert sum(work.flops(p.shapes(r)) for r in p.rounds) == useful
+    useful = sum(2 * m * k * n for t in _load("configs", MIXES[name][0])
+                 ["tenants"] for _, m, k, n in t["layers"])
+    assert sum(work.flops(p, r) for r in p.rounds) == useful
+
+
+@pytest.mark.parametrize("name", MIXES)
+def test_warm_up_runs_one_round_of_each_signature(plans, name):
+    """Counted on the operands' shapes alone, with no array made."""
+    p = plans[name]
+    xs, ws, cut = jax.eval_shape(operands.generator(p), operands.key(1))
+    calls = replay.calls(p, {gemm.ENTRY: "fused"}, cut, ws)
+    assert len({rnd.signature() for rnd in calls}) == WARMED[name]
+    assert all(len(rnd.calls) == 1 for rnd in calls)
 
 
 def test_solo_rounds_hold_one_tenant_in_schedule_order(plans):
@@ -78,7 +96,8 @@ def test_solo_rounds_hold_one_tenant_in_schedule_order(plans):
 
 def test_rounds_from_a_small_trace():
     part = SimpleNamespace
-    layers = (plan.Layer("a", "l0", 10, 4, 4), plan.Layer("b", "l0", 3, 4, 4))
+    layers = (plan.layer("a", ["l0", 10, 4, 4]),
+              plan.layer("b", ["l0", 3, 4, 4]))
     trace = [SimpleNamespace(tenant="a", layer_index=0, compute_start=0.0,
                              compute_end=4.0, partition=part(col_start=64)),
              SimpleNamespace(tenant="b", layer_index=0, compute_start=1.0,
@@ -92,7 +111,7 @@ def test_rounds_from_a_small_trace():
 
 
 def test_a_layer_in_two_segments_is_refused():
-    layers = (plan.Layer("a", "l0", 4, 4, 4),)
+    layers = (plan.layer("a", ["l0", 4, 4, 4]),)
     ev = dict(tenant="a", layer_index=0,
               partition=SimpleNamespace(col_start=0))
     trace = [SimpleNamespace(compute_start=0.0, compute_end=1.0, **ev),
@@ -114,12 +133,16 @@ def test_a_config_the_program_disagrees_with_is_refused():
 # ---------------------------------------------------------------------------
 
 def test_flop_and_byte_count_of_a_known_shape():
-    shapes = [(2, 3, 4), (5, 7, 11)]
-    assert work.flops(shapes) == 2 * 2 * 3 * 4 + 2 * 5 * 7 * 11
-    assert work.bytes_moved([(2, 3, 4)]) == 2 * 3 * 2 + 3 * 4 * 2 + 2 * 4 * 4
+    layers = (plan.layer("a", ["l0", 2, 3, 4]),
+              plan.layer("b", ["l0", 9, 7, 11]))
+    both = (plan.Slice(0, 0, 2), plan.Slice(1, 2, 7))
+    p = plan.Plan(layers, (both, both[:1]))
+    assert work.flops(p, both) == 2 * 2 * 3 * 4 + 2 * 5 * 7 * 11
+    one = p.rounds[1]
+    assert work.bytes_moved(p, one) == 2 * 3 * 2 + 3 * 4 * 2 + 2 * 4 * 4
     # memory bound at these peaks: 68 bytes / 1 B/s beats 48 flops / 1e3
-    assert work.least_seconds([(2, 3, 4)], 1e3, 1.0) == 68.0
-    assert work.least_seconds([(2, 3, 4)], 1.0, 1e3) == 48.0
+    assert work.least_seconds(p, one, 1e3, 1.0) == 68.0
+    assert work.least_seconds(p, one, 1.0, 1e3) == 48.0
 
 
 def test_peaks_are_keyed_by_device_kind():
@@ -149,6 +172,15 @@ def test_list_finds_the_cells_without_a_device():
                          cwd=ROOT, capture_output=True, text=True, check=True)
     assert [ln.split(":")[0] for ln in out.stdout.splitlines()] == \
         [w["name"] for w in catalog.benchmark()["workloads"]]
+
+
+def test_a_row_names_its_kind():
+    assert kinds.of_row(["l0", 2, 3, 4]) is gemm
+    assert kinds.of_row({"kind": "gemm"}) is gemm
+    with pytest.raises(KeyError, match="no layer kind 'no_such'"):
+        kinds.of_row({"kind": "no_such"})
+    with pytest.raises(ValueError, match="identifier"):
+        kinds.load("../gemm")
 
 
 def test_seeds_far_apart_give_other_operands():
@@ -240,7 +272,7 @@ def _two_light_rounds(plans) -> plan.Plan:
         rnd = []
         for s in full.rounds[r]:
             la = full.layers[s.layer]
-            layers.append(dataclasses.replace(la, t=s.rows))
+            layers.append(dataclasses.replace(la, rows=s.rows))
             rnd.append(plan.Slice(len(layers) - 1, 0, s.rows))
         rounds.append(tuple(rnd))
     return plan.Plan(tuple(layers), tuple(rounds))
@@ -256,10 +288,28 @@ def _interpret_gemm():
     return functools.partial(fused_tenant_gemm, interpret=True)
 
 
-def _run(small, gemm, trace=False, seconds=0.5):
+def _run(small, fused, trace=False, seconds=0.5, **given):
     cell = catalog.cell("light-closed")
-    return run.run_cell(cell, 2 ** 31 + 17, seconds, trace, gemm=gemm,
-                        plan=small)
+    return run.run_cell(cell, 2 ** 31 + 17, seconds, trace,
+                        given={gemm.ENTRY: fused, **given}, plan=small)
+
+
+# sha256 of the shapes, types and bytes of every array ``operands.make``
+# returned for the two light rounds, in order, recorded from the harness
+# that knew only GEMM layers
+DRAWN = {2 ** 31 + 17:
+         "1e81d81f218a9b8aed07dfedc8e326f703f59b48641c6bf0f4c9e91a6df71d6e",
+         7: "e54d15926a5c9d110be9ba774a5df3b9cad9946fa00899085a2204f5e6840677"}
+
+
+@pytest.mark.parametrize("seed", DRAWN)
+def test_gemm_operands_are_the_draw_they_were(small, seed):
+    leaves = jax.tree_util.tree_leaves(operands.make(small, seed))
+    h = hashlib.sha256()
+    for a in map(np.asarray, leaves):
+        h.update(str((a.shape, a.dtype.name)).encode())
+        h.update(a.tobytes())
+    assert len(leaves) == 12 and h.hexdigest() == DRAWN[seed]
 
 
 def test_rehearsal_of_two_light_rounds(small):
@@ -279,7 +329,7 @@ def test_traced_rehearsal_reads_the_per_layer_metrics(small):
     # the peaks exist only for a TPU, so the shares of peaks stay silent
     assert set(res["metrics"]) == {"schedule_ms", "launch_host_ms",
                                    "lowerings_in_window", "idle_share"}
-    assert res["metrics"]["lowerings_in_window"]["value"] > 0  # compact
+    assert res["metrics"]["lowerings_in_window"]["value"] == 0
     assert 0 < res["device"]["busy_s"] < res["device"]["window_s"]
     assert res["breakdown"]["device_ops"] and res["breakdown"]["idle_gaps"]
 
@@ -319,9 +369,111 @@ def test_a_broken_timed_path_is_not_correct(small, fault):
 
 
 def test_the_int8_control_is_not_correct(small):
-    res = _run(small, check.int8_gemm)
+    res = _run(small, gemm.control())
     assert res["correct"] is False
-    assert res["checks"]["worst_rel_err"]["value"] > 3 * check.REL_ERR_LIMIT
+    assert res["checks"]["worst_rel_err"]["value"] > 3 * gemm.REL_ERR_LIMIT
+
+
+# ---------------------------------------------------------------------------
+# the seam: kinds of another shape and another program entry in one round
+# ---------------------------------------------------------------------------
+
+def _layer(kind, tenant, row):
+    name, rows, spec = kind.parse(row)
+    return plan.Layer(tenant, name, kind, rows, spec)
+
+
+@pytest.fixture(scope="module")
+def experts():
+    """A GEMM tenant beside routed experts of 1, 7 and 40 rows, all three
+    in one slice; then a second expert layer whose slices split experts."""
+    layers = (plan.layer("a", ["fc", 16, 256, 128]),
+              _layer(kind_experts, "b", {"kind": "kind_experts",
+                                         "name": "moe", "experts": [1, 7, 40],
+                                         "k": 128, "n": 384}),
+              _layer(kind_experts, "b", {"kind": "kind_experts",
+                                         "name": "moe2", "experts": [3, 2, 9],
+                                         "k": 384, "n": 128}))
+    return plan.Plan(layers, ((plan.Slice(0, 0, 16), plan.Slice(1, 0, 48)),
+                              (plan.Slice(2, 0, 4),),
+                              (plan.Slice(2, 4, 14),)))
+
+
+def test_expert_pieces_share_the_fused_call(experts):
+    xs, ws, cut = operands.make(experts, 3)
+    calls = replay.calls(experts, {gemm.ENTRY: "fused"}, cut, ws)
+    first = calls[0].calls
+    assert len(first) == 1 and first[0][0] == "fused"
+    assert [x.shape[0] for x in first[0][1][0]] == [16, 1, 7, 40]
+    assert [x.shape[0] for x in calls[1].calls[0][1][0]] == [3, 1]
+    assert [x.shape[0] for x in calls[2].calls[0][1][0]] == [1, 9]
+    assert calls[0].slots == ((0, 0, 1), (0, 1, 3))
+    assert work.flops(experts, experts.rounds[0]) == \
+        2 * 16 * 256 * 128 + 2 * 48 * 128 * 384
+
+
+def test_rehearsal_of_routed_experts(experts):
+    res = _run(experts, _interpret_gemm())
+    assert res["correct"] is True and res["attempted"] == 3
+    assert list(res["checks"]) == ["worst_rel_err", "rows_off",
+                                   "layers_compared"]
+    assert 0 < res["checks"]["worst_rel_err"]["value"] < 1e-5
+
+
+def test_an_altered_expert_piece_is_caught(experts):
+    good = _interpret_gemm()
+
+    def broken(xs, ws, **kw):
+        outs = list(good(xs, ws, **kw))
+        if len(xs) == 4:       # the 7-row expert, third piece of round 0
+            outs[2] = outs[2].at[3, 5].add(1.0)
+        return outs
+
+    res = _run(experts, broken)
+    assert res["correct"] is False and res["failed"] == 1
+    assert res["checks"]["rows_off"]["value"] == 0
+
+
+@pytest.fixture(scope="module")
+def mixed():
+    """Attention slices, through their own entry, in the rounds of GEMM
+    slices: a round with both, then one with attention alone."""
+    attn = {"kind": "kind_attention", "name": "core", "t": 24, "s": 40,
+            "d": 128}
+    layers = (plan.layer("a", ["fc", 16, 256, 128]),
+              _layer(kind_attention, "b", attn),
+              plan.layer("c", ["fc", 8, 128, 256]))
+    return plan.Plan(layers, ((plan.Slice(1, 0, 10), plan.Slice(0, 0, 16),
+                               plan.Slice(2, 0, 8)),
+                              (plan.Slice(1, 10, 24),)))
+
+
+def test_rehearsal_with_a_second_entry(mixed):
+    res = _run(mixed, _interpret_gemm())
+    assert res["correct"] is True and res["attempted"] == 3
+    assert list(res["checks"]) == ["worst_rel_err", "worst_rel_err.attention",
+                                   "rows_off", "layers_compared"]
+    assert 0 < res["checks"]["worst_rel_err.attention"]["value"] < 1e-5
+    xs, ws, cut = operands.make(mixed, 3)
+    calls = replay.calls(mixed, {gemm.ENTRY: "fused", kind_attention.ENTRY:
+                                 "attention"}, cut, ws)
+    # the attention slice comes first by its column, so its call does
+    assert [fn for fn, _ in calls[0].calls] == ["attention", "fused"]
+    assert calls[0].slots == ((0, 0, 1), (1, 0, 1), (1, 1, 1))
+
+
+@pytest.mark.parametrize("fault", ["altered", "control"])
+def test_a_broken_second_entry_is_caught(mixed, fault):
+    def altered(qs, ks, vs):
+        outs = kind_attention.attention(qs, ks, vs)
+        return [outs[0].at[2, 3].add(0.5)] + outs[1:]
+
+    res = _run(mixed, _interpret_gemm(), **{kind_attention.ENTRY: {
+        "altered": altered, "control": kind_attention.control()}[fault]})
+    assert res["correct"] is False and res["failed"] == 1
+    assert res["checks"]["worst_rel_err.attention"]["value"] > \
+        3 * kind_attention.REL_ERR_LIMIT
+    assert res["checks"]["worst_rel_err"]["value"] < gemm.REL_ERR_LIMIT
 
 
 # ---------------------------------------------------------------------------
@@ -353,10 +505,11 @@ def test_largest_heavy_closed_round_compiles_for_v5e(plans, one_chip,
                                partitioned_matmul)
 
     p = plans["heavy-closed"]
-    key = {"most_bytes": lambda r: work.bytes_moved(p.shapes(r)),
-           "most_tenants": lambda r: (len(r), work.bytes_moved(p.shapes(r)))
+    key = {"most_bytes": lambda r: work.bytes_moved(p, r),
+           "most_tenants": lambda r: (len(r), work.bytes_moved(p, r))
            }[which]
-    shapes = p.shapes(max(p.rounds, key=key))
+    shapes = tuple(gemm.shape(p.layers[s.layer], s.row0, s.row1)
+                   for s in max(p.rounds, key=key))
     bt, bk, bn = autotune_blocks(shapes, "bfloat16", "bfloat16",
                                  grid_mode="compact")
     T = -(-max(t for t, _, _ in shapes) // bt) * bt

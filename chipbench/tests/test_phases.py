@@ -14,6 +14,7 @@ import jax
 import pytest
 
 from chipbench import catalog, devtrace, phases, plan
+from chipbench.kinds import gemm
 from chipbench.tests.test_chipbench import _two_light_rounds
 
 ROOT = Path(__file__).resolve().parents[2]
@@ -117,8 +118,8 @@ def test_a_traced_window_on_the_cpu_reads_the_phases(small):
     from repro.kernels import fused_tenant_gemm
 
     res = phases.measure(catalog.cell("light-closed"), 2 ** 31 + 17, 0.5,
-                         gemm=functools.partial(fused_tenant_gemm,
-                                                interpret=True),
+                         given={gemm.ENTRY: functools.partial(
+                             fused_tenant_gemm, interpret=True)},
                          plan=small)
     assert res["correct"] is True
     m = res["metrics"]

@@ -1,30 +1,32 @@
-"""The benchmark's own count of a GEMM's operations and bytes.
+"""The benchmark's own count of a round's operations and bytes.
 
-Taken from the unpadded shapes, so it reads the same work whatever
-implements the call: ``2 * t * k * n`` operations, and the bytes of a bf16
-``(t, k)`` activation slice, a bf16 ``(k, n)`` weight and an f32 ``(t, n)``
-output, each moved once.
+Each slice's kind counts its operations and bytes from the unpadded shapes
+(``chipbench.kinds``), so the count reads the same work whatever implements
+the call.
 """
 
 from __future__ import annotations
 
-from typing import Iterable
+from typing import Sequence
 
-X_BYTES = 2    # bf16 activations
-W_BYTES = 2    # bf16 weights
-OUT_BYTES = 4  # f32 outputs
+from chipbench.plan import Plan, Slice
 
 
-def flops(shapes: Iterable[tuple[int, int, int]]) -> int:
-    return sum(2 * t * k * n for t, k, n in shapes)
+def _work(plan: Plan, rnd: Sequence[Slice]) -> list[tuple[int, int]]:
+    return [plan.layers[s.layer].kind.work(plan.layers[s.layer], s.row0,
+                                           s.row1) for s in rnd]
 
 
-def bytes_moved(shapes: Iterable[tuple[int, int, int]]) -> int:
-    return sum(t * k * X_BYTES + k * n * W_BYTES + t * n * OUT_BYTES
-               for t, k, n in shapes)
+def flops(plan: Plan, rnd: Sequence[Slice]) -> int:
+    return sum(f for f, _ in _work(plan, rnd))
 
 
-def least_seconds(shapes, peak_flops: float, peak_bytes: float) -> float:
+def bytes_moved(plan: Plan, rnd: Sequence[Slice]) -> int:
+    return sum(b for _, b in _work(plan, rnd))
+
+
+def least_seconds(plan: Plan, rnd: Sequence[Slice], peak_flops: float,
+                  peak_bytes: float) -> float:
     """The roofline: the larger of compute time and memory time at peak."""
-    shapes = list(shapes)
-    return max(flops(shapes) / peak_flops, bytes_moved(shapes) / peak_bytes)
+    return max(flops(plan, rnd) / peak_flops,
+               bytes_moved(plan, rnd) / peak_bytes)
