@@ -1,6 +1,13 @@
 """The paper's primary contribution: DNNG model, Algorithm 1, scheduler, dataflow."""
 
-from repro.core.dnng import DNNG, LayerShape, chain
+from repro.core.dnng import (
+    DNNG,
+    AttentionLayer,
+    ExpertLayer,
+    LayerShape,
+    Rope,
+    chain,
+)
 from repro.core.partition import (
     ArrayShape,
     Assignment,
@@ -19,7 +26,7 @@ from repro.core.scheduler import (
 from repro.core.dataflow import GEMM, DataflowCost, ws_cost, utilization
 
 __all__ = [
-    "DNNG", "LayerShape", "chain",
+    "DNNG", "LayerShape", "ExpertLayer", "AttentionLayer", "Rope", "chain",
     "ArrayShape", "Assignment", "Partition", "PartitionSet",
     "partition_calculation", "task_assignment",
     "DynamicScheduler",

@@ -18,6 +18,13 @@ Every layer lowers to a GEMM for the weight-stationary systolic array:
 
 Fully connected / recurrent layers are expressed with R=S=1, H=W=P=Q=1 and the
 batch/time steps folded into N.
+
+Two layers of a transformer block are not one GEMM: a routed-expert layer
+(:class:`ExpertLayer`) and an attention core (:class:`AttentionLayer`).  Each
+lowers to several weight-stationary GEMMs (``gemms``), which the simulator
+prices one by one and sums, so such a layer still runs in one compute
+interval; each also answers the aggregate ``gemm_m``/``gemm_k``/``gemm_n``,
+``macs``, ``opr`` and ``weight_bytes`` that the policies read.
 """
 
 from __future__ import annotations
@@ -121,6 +128,11 @@ class LayerShape:
         return self.N * self.P * self.Q
 
     @property
+    def gemms(self) -> tuple[tuple[int, int, int], ...]:
+        """The layer's GEMMs as ``(T, K, N)``: here the one lowered GEMM."""
+        return ((self.gemm_m, self.gemm_k, self.gemm_n),)
+
+    @property
     def weight_bytes(self) -> int:
         return 2 * self.gemm_k * self.gemm_n  # bf16/int16 as in Scale-Sim configs
 
@@ -161,6 +173,211 @@ class LayerShape:
                           R=1, S=1, H=1, W=1, P=1, Q=1, name=name)
 
 
+def _gemm_macs(gemms) -> int:
+    return sum(t * k * n for t, k, n in gemms)
+
+
+@dataclasses.dataclass(frozen=True)
+class ExpertLayer:
+    """The routed experts a chip holds of one sparse MLP layer.
+
+    Expert ``held[i]`` is a SiLU-gated MLP, ``hidden -> 2 * width`` (gate and
+    up, one weight) then ``width -> hidden`` (down), applied to the
+    ``rows[i]`` token rows the router sent it.  The router scores all
+    ``n_experts`` and picks ``top_k`` per token; a chip holding only some
+    of them computes their share of the layer.  Lowered to two GEMMs per
+    held expert with rows, at that expert's rows.  In aggregate the layer
+    streams every routed row (``gemm_m``) against the held experts' gate/up
+    columns (``gemm_n``, the widest of its GEMMs).
+    """
+
+    name: str
+    hidden: int
+    width: int
+    rows: tuple[int, ...]
+    held: tuple[int, ...] = ()
+    n_experts: int = 0
+    top_k: int = 1
+
+    def __post_init__(self) -> None:
+        if self.hidden < 1 or self.width < 1 or not any(self.rows):
+            raise ValueError(f"ExpertLayer {self.name!r}: needs a hidden "
+                             "and expert width, one row count a held "
+                             "expert and a routed row")
+        if any(not isinstance(r, int) or r < 0 for r in self.rows):
+            raise ValueError(f"ExpertLayer {self.name!r}: row counts must "
+                             f"be ints >= 0, got {self.rows}")
+        held = self.held or tuple(range(len(self.rows)))
+        n = self.n_experts or len(held)
+        if len(held) != len(self.rows) or len(set(held)) != len(held) \
+                or not all(0 <= e < n for e in held):
+            raise ValueError(f"ExpertLayer {self.name!r}: held experts "
+                             f"{held} do not match {len(self.rows)} row "
+                             f"counts among {n} experts")
+        object.__setattr__(self, "held", tuple(held))
+        object.__setattr__(self, "n_experts", n)
+
+    @property
+    def gemms(self) -> tuple[tuple[int, int, int], ...]:
+        out = []
+        for r in self.rows:
+            if r:
+                out += [(r, self.hidden, 2 * self.width),
+                        (r, self.width, self.hidden)]
+        return tuple(out)
+
+    @property
+    def gemm_m(self) -> int:
+        return sum(self.rows)
+
+    @property
+    def gemm_k(self) -> int:
+        return self.hidden
+
+    @property
+    def gemm_n(self) -> int:
+        return len(self.held) * 2 * self.width
+
+    @property
+    def macs(self) -> int:
+        return _gemm_macs(self.gemms)
+
+    @property
+    def opr(self) -> int:
+        return self.macs
+
+    @property
+    def weight_bytes(self) -> int:
+        return 2 * len(self.held) * 3 * self.hidden * self.width
+
+    @property
+    def ifmap_elems(self) -> int:
+        return self.gemm_m * self.hidden
+
+    @property
+    def ofmap_elems(self) -> int:
+        return self.gemm_m * self.hidden
+
+
+@dataclasses.dataclass(frozen=True)
+class Rope:
+    """Rotary position embedding of an attention layer: ``kind`` "default"
+    (inverse frequencies ``theta ** (-2i / head_dim)``) or "yarn" (those,
+    blended toward ``1 / factor`` of themselves between ``beta_fast`` and
+    ``beta_slow`` rotations over ``original_max_position_embeddings``, with
+    ``attention_factor`` multiplying cos and sin)."""
+
+    kind: str = "default"
+    theta: float = 10000.0
+    factor: float = 1.0
+    original_max_position_embeddings: int = 0
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    attention_factor: float = 1.0
+
+    def __post_init__(self) -> None:
+        if self.kind not in ("default", "yarn"):
+            raise ValueError(f"rope kind must be 'default' or 'yarn', got "
+                             f"{self.kind!r}")
+
+
+def visible_pairs(length: int, window: int) -> int:
+    """(query, key) pairs a causal prompt of ``length`` tokens attends,
+    each query seeing itself and the ``window - 1`` keys before it
+    (``window`` 0: every key before it)."""
+    if not window or window >= length:
+        return length * (length + 1) // 2
+    return window * (window + 1) // 2 + (length - window) * window
+
+
+@dataclasses.dataclass(frozen=True)
+class AttentionLayer:
+    """The attention core of a prefill step: ``heads`` query heads sharing
+    ``kv_heads`` key/value heads, each ``head_dim`` wide, over packed
+    prompts of ``seq_lens`` tokens, causal within each prompt, windowed to
+    ``window`` keys (0: full), with rotary embedding ``rope``.
+
+    In the weight-stationary lowering a prompt's keys (for QKᵀ) and values
+    (for PV) are the stationary operands and its queries stream: per prompt
+    and key/value head, QKᵀ streams the group's ``heads / kv_heads`` query
+    heads' rows against the visible keys, and PV the scores against the
+    values.  A band of visible keys is lowered at its mean width, the
+    visible pairs over the prompt's length rounded up.  In aggregate the
+    layer streams every token's query row (``gemm_m``, ``gemm_k`` its
+    width) to an output of the same width (``gemm_n``).
+    """
+
+    name: str
+    heads: int
+    kv_heads: int
+    head_dim: int
+    seq_lens: tuple[int, ...]
+    window: int = 0
+    rope: Rope = Rope()
+
+    def __post_init__(self) -> None:
+        if min(self.heads, self.kv_heads, self.head_dim) < 1 \
+                or self.heads % self.kv_heads:
+            raise ValueError(f"AttentionLayer {self.name!r}: {self.heads} "
+                             f"query heads do not share {self.kv_heads} "
+                             "key/value heads evenly")
+        if not self.seq_lens or min(self.seq_lens) < 1 or self.window < 0:
+            raise ValueError(f"AttentionLayer {self.name!r}: needs prompts "
+                             "of at least one token and a window >= 0")
+
+    @property
+    def tokens(self) -> int:
+        return sum(self.seq_lens)
+
+    @property
+    def pairs(self) -> int:
+        """Visible (query, key) pairs over every prompt, per head."""
+        return sum(visible_pairs(n, self.window) for n in self.seq_lens)
+
+    @property
+    def gemms(self) -> tuple[tuple[int, int, int], ...]:
+        group = self.heads // self.kv_heads
+        out = []
+        for n in self.seq_lens:
+            keys = -(-visible_pairs(n, self.window) // n)
+            out += [(group * n, self.head_dim, keys),
+                    (group * n, keys, self.head_dim)] * self.kv_heads
+        return tuple(out)
+
+    @property
+    def gemm_m(self) -> int:
+        return self.tokens
+
+    @property
+    def gemm_k(self) -> int:
+        return self.heads * self.head_dim
+
+    @property
+    def gemm_n(self) -> int:
+        return self.heads * self.head_dim
+
+    @property
+    def macs(self) -> int:
+        return _gemm_macs(self.gemms)
+
+    @property
+    def opr(self) -> int:
+        return self.macs
+
+    @property
+    def weight_bytes(self) -> int:
+        """The stationary operands: every key and value, bf16."""
+        return 2 * 2 * self.tokens * self.kv_heads * self.head_dim
+
+    @property
+    def ifmap_elems(self) -> int:
+        return self.tokens * (self.heads + 2 * self.kv_heads) * self.head_dim
+
+    @property
+    def ofmap_elems(self) -> int:
+        return self.tokens * self.heads * self.head_dim
+
+
 @dataclasses.dataclass(frozen=True)
 class DNNG:
     """A DNN graph: a named chain/DAG of layers with an arrival time (§2.1).
@@ -172,7 +389,7 @@ class DNNG:
     """
 
     name: str
-    layers: tuple[LayerShape, ...]
+    layers: tuple[LayerShape | ExpertLayer | AttentionLayer, ...]
     arrival_time: float = 0.0
     edges: tuple[tuple[int, int], ...] | None = None
 

@@ -63,11 +63,13 @@ class StageModel:
     bytes_per_elem: int = 2
 
     def stage_in_s(self, layer: LayerShape) -> float:
-        elems = layer.gemm_k * layer.gemm_n + layer.gemm_m * layer.gemm_k
+        # each of the layer's GEMMs stages its stationary and streamed
+        # operands (a plain layer has one)
+        elems = sum(k * n + m * k for m, k, n in layer.gemms)
         return elems * self.bytes_per_elem / self.dram_bw_bytes
 
     def stage_out_s(self, layer: LayerShape) -> float:
-        return (layer.gemm_m * layer.gemm_n * self.bytes_per_elem
+        return (sum(m * n for m, _, n in layer.gemms) * self.bytes_per_elem
                 / self.dram_bw_bytes)
 
 

@@ -1,5 +1,14 @@
-"""Pallas TPU kernels: the paper's partitioned-WS GEMM (+ oracle & wrappers)."""
+"""Pallas TPU kernels: the paper's partitioned-WS GEMM (+ oracle & wrappers),
+routed experts on it, and prefill attention."""
 
+from repro.kernels.attention import (
+    attention_table,
+    live_blocks,
+    packed_positions,
+    prefill_attention,
+    rope_inv_freq,
+)
+from repro.kernels.moe import moe_block, route, routed_experts
 from repro.kernels.ops import (
     BLOCK_CANDIDATES,
     FusedGemmStats,
@@ -24,13 +33,21 @@ __all__ = [
     "FusedGemmStats",
     "GRID_MODES",
     "VMEM_BUDGET_BYTES",
+    "attention_table",
     "autotune_blocks",
     "block_vmem_bytes",
     "build_owner_map",
     "fused_tenant_gemm",
     "grid_accounting",
     "live_block_tables",
+    "live_blocks",
     "matmul_ref",
+    "moe_block",
+    "packed_positions",
     "partitioned_matmul",
     "partitioned_matmul_ref",
+    "prefill_attention",
+    "rope_inv_freq",
+    "route",
+    "routed_experts",
 ]

@@ -185,6 +185,11 @@ def fused_tenant_gemm(xs: Sequence[jax.Array], ws: Sequence[jax.Array], *,
 
         shapes = tuple((int(x.shape[0]), int(x.shape[1]), int(w.shape[1]))
                        for x, w in zip(xs, ws))
+        # a tenant with no rows is a dead column slice, but a call in which
+        # every tenant has none has no grid to run
+        if not any(t for t, _, _ in shapes):
+            raise ValueError("every tenant has 0 rows: nothing to compute; "
+                             "pass only tenants that have rows")
         # the packed operands keep these types; the kernel promotes a mixed
         # x/w pair to a common type, so the autotuner budgets for that type
         # BEFORE the VMEM filter and byte accounting, and never approves

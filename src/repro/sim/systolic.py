@@ -13,6 +13,8 @@ import dataclasses
 import functools
 from typing import Sequence
 
+import numpy as np
+
 from repro.core.dataflow import (
     GEMM,
     BatchCost,
@@ -45,13 +47,17 @@ class SystolicConfig:
 
 @functools.lru_cache(maxsize=1 << 16)
 def layer_cost(layer: LayerShape, part: Partition) -> DataflowCost:
-    """Cycle/access breakdown of one layer on one partition.
+    """Cycle/access breakdown of one layer on one partition: the sum over
+    the GEMMs it lowers to (``layer.gemms``; one for a plain layer), run one
+    after another on the partition.
 
     Memoized on top of the (also memoized) :func:`ws_cost`: the extra LRU
     level skips even the layer→GEMM lowering for the exact repeats the
     scheduler's rebalance loop generates.
     """
-    return ws_cost(GEMM.of_layer(layer), part)
+    costs = [ws_cost(GEMM(*g), part) for g in layer.gemms]
+    return DataflowCost(*(sum(getattr(c, f.name) for c in costs)
+                          for f in dataclasses.fields(DataflowCost)))
 
 
 def layer_cycles(layer: LayerShape, part: Partition) -> int:
@@ -64,14 +70,25 @@ def layer_cost_batch(layers: Sequence[LayerShape],
                      ) -> BatchCost:
     """Vectorized :func:`layer_cost` over paired (layer, partition)
     candidates — one :func:`repro.core.dataflow.ws_cost_batch` NumPy pass
-    after the layer→GEMM lowering.  Bit-identical to the scalar path.
+    over every layer's GEMMs, each priced on its layer's partition, then
+    each layer's rows summed.  Bit-identical to the scalar path.
 
     ``bw_shares`` (optional per-pair memory-bandwidth shares) fills the
     table's ``dram_stall_elems`` column — zeros at share 1.0, and the
     int64 columns are untouched by it (see
     :func:`repro.core.dataflow.ws_cost_batch`)."""
-    return ws_cost_batch([GEMM.of_layer(layer) for layer in layers], parts,
-                         bw_shares=bw_shares)
+    counts = [len(layer.gemms) for layer in layers]
+    owner = np.repeat(np.arange(len(layers)), counts)
+    flat = ws_cost_batch(
+        [GEMM(*g) for layer in layers for g in layer.gemms],
+        [parts[i] for i in owner],
+        bw_shares=None if bw_shares is None
+        else [bw_shares[i] for i in owner])
+    starts = np.cumsum(counts) - counts
+    return BatchCost(**{
+        f.name: None if getattr(flat, f.name) is None
+        else np.add.reduceat(getattr(flat, f.name), starts)
+        for f in dataclasses.fields(BatchCost)})
 
 
 class _BatchTimeOracle:

@@ -27,6 +27,7 @@ Calibration notes (EXPERIMENTS.md §Fig9):
 from __future__ import annotations
 
 from repro.core.dnng import DNNG, LayerShape, chain
+from repro.sim import mellum
 
 Conv = LayerShape.conv
 FC = LayerShape.fc
@@ -285,7 +286,8 @@ WORKLOADS = {
 
 # Table-1 models individually, for per-job sampling by the open-loop traffic
 # generator (`repro.traffic.arrivals`): each arrival picks ONE model from a
-# pool instead of replaying the whole closed workload at t≈0.
+# pool instead of replaying the whole closed workload at t≈0; then the models
+# beyond Table 1 (`repro.sim.mellum`), in no pool.
 MODELS = {
     "AlexNet": alexnet,
     "ResNet50": resnet50,
@@ -299,14 +301,16 @@ MODELS = {
     "GoogleTranslate": google_translate,
     "DeepVoice": deep_voice,
     "HandwritingLSTM": handwriting_lstm,
+    mellum.NAME: mellum.mellum2_12b_a2_5b,
 }
 
+_HEAVY = ("AlexNet", "ResNet50", "GoogleNet", "SA_CNN", "SA_LSTM", "NCF",
+          "AlphaGoZero", "Transformer")
+_LIGHT = ("MelodyLSTM", "GoogleTranslate", "DeepVoice", "HandwritingLSTM")
 MODEL_POOLS = {
-    "heavy": ("AlexNet", "ResNet50", "GoogleNet", "SA_CNN", "SA_LSTM",
-              "NCF", "AlphaGoZero", "Transformer"),
-    "light": ("MelodyLSTM", "GoogleTranslate", "DeepVoice",
-              "HandwritingLSTM"),
-    "all": tuple(MODELS),
+    "heavy": _HEAVY,
+    "light": _LIGHT,
+    "all": _HEAVY + _LIGHT,
 }
 
 

@@ -125,6 +125,50 @@ def test_block_over_budget_is_refused_by_v5e_compiler(one_chip):
 
 
 # ---------------------------------------------------------------------------
+# Mellum2's new kernels at its published widths
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("window", [1024, 0], ids=["windowed", "full"])
+def test_mellum_prefill_attention_compiles(one_chip, window):
+    from repro.kernels.attention import _attention_call, attention_table
+    from repro.sim import mellum
+
+    table = attention_table(mellum.PROMPTS, window)
+    n = sum(mellum.PROMPTS)
+
+    def s(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    compiled = jax.jit(
+        lambda *a: _attention_call(*a, block_q=256, block_k=256,
+                                   interpret=False)).lower(
+        s(table.shape, jnp.int32), s((), jnp.int32),
+        s((n, 4096), jnp.bfloat16), s((n, 512), jnp.bfloat16),
+        s((n, 512), jnp.bfloat16), *[s((n,), jnp.int32)] * 4,
+        s((64,), jnp.float32), s((), jnp.float32)).compile()
+    assert "attention_prefill" in compiled.as_text()
+
+
+@pytest.mark.parametrize("proj", ["gate_up", "down"])
+def test_mellum_routed_experts_compile(one_chip, proj):
+    """The dense grid of each of ``routed_experts``' two fused calls, at the
+    row counts of the layer with the busiest expert."""
+    from repro.sim import mellum
+
+    rows = max((mellum.routed_rows(i) for i in range(mellum.LAYERS)),
+               key=max)
+    k, n = {"gate_up": (2304, 1792), "down": (896, 2304)}[proj]
+    gemms = [(r, k, n) for r in rows if r]
+    dt = str(jnp.dtype(jnp.bfloat16))
+    compiled = _compile(one_chip, gemms, jnp.bfloat16, grid_mode="dense",
+                        blocks=autotune_blocks(tuple(gemms), dt, dt,
+                                               grid_mode="dense"))
+    assert "tenant_gemm_dense" in compiled.as_text()
+    assert compiled.memory_analysis().argument_size_in_bytes >= \
+        64 * k * n * 2
+
+
+# ---------------------------------------------------------------------------
 # chip_smoke.py's host logic and replay, on the CPU
 # ---------------------------------------------------------------------------
 
